@@ -18,6 +18,7 @@ from benchmarks import (engine_scale, fig4_multitenancy, fig5_6_8_policies,
                         fig7_pareto, fig9_10_fairness, perf_compare,
                         quant_fidelity, roofline, serving_throughput,
                         table1_load_vs_infer)
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = {
     "table1_load_vs_infer": table1_load_vs_infer,
@@ -63,6 +64,7 @@ def main() -> None:
         list_benchmarks()
         return
     names = sys.argv[1:] or list(MODULES)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name in names:

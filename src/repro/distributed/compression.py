@@ -118,11 +118,5 @@ def compressed_allreduce_demo(values: jnp.ndarray, mesh) -> jnp.ndarray:
     def body(v):
         return compressed_psum(v, axis)
 
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis),
-                           out_specs=P())
-    else:  # older jax: the pre-promotion experimental API
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
-                       check_rep=False)
-    return fn(values)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=P())(values)

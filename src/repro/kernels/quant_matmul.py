@@ -78,7 +78,7 @@ def quant_matmul(
     G = scales.shape[0]
     group = K // G
     lead = x.shape[:-1]
-    M = int(jnp.prod(jnp.array(lead))) if lead else 1
+    M = math.prod(lead)
     x2 = x.reshape(M, K)
 
     bm = min(block_m, max(8, M))

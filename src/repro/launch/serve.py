@@ -14,6 +14,7 @@ import argparse
 import numpy as np
 
 from repro.core.policies import available_policies
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import Batcher, Request
 from repro.serving.api import (BatchingSpec, EdgeServer, LoaderSpec,
                                ServingConfig, TenantSpec)
@@ -37,6 +38,7 @@ def main() -> None:
                     "shard per chip, loads stage per shard under "
                     "per-device budgets")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(args.seed)
     server = EdgeServer.build(ServingConfig(

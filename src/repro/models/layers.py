@@ -15,6 +15,7 @@ from jax import lax
 from repro.kernels import ops
 from repro.distributed.ctx import hint
 from repro.models.config import ModelConfig
+from repro.quant.quantize import PlacedQuant
 
 
 # ---------------------------------------------------------------------------
@@ -26,7 +27,11 @@ def _is_q(w) -> bool:
 
 
 def mm(x: jnp.ndarray, w) -> jnp.ndarray:
-    """x @ w for dense or quantized ({"q","s"}) 2-D weights."""
+    """x @ w for dense or quantized ({"q","s"} or mesh-placed) 2-D
+    weights."""
+    if isinstance(w, PlacedQuant):
+        return ops.quant_matmul(x, w.q, w.s, out_dtype=x.dtype,
+                                mesh=w.mesh, spec=w.spec)
     if _is_q(w):
         return ops.quant_matmul(x, w["q"], w["s"], out_dtype=x.dtype)
     return x @ w
@@ -36,6 +41,8 @@ def dense_w(w) -> jnp.ndarray:
     """Materialize a (possibly quantized) weight densely — used for >2-D
     expert tensors and embedding-style contractions where the fused kernel
     doesn't apply."""
+    if isinstance(w, PlacedQuant):
+        w = {"q": w.q, "s": w.s}
     if _is_q(w):
         from repro.quant.quantize import dequantize_leaf
 

@@ -14,10 +14,12 @@ hurts fidelity disproportionately.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.kernels import ops
 
@@ -84,6 +86,51 @@ def quantize_params(params: PyTree, *, bits: int = 8,
         return _quantize_leaf(w, bits, g)
 
     return jax.tree_util.tree_map_with_path(visit, params)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass(frozen=True)
+class PlacedQuant:
+    """A quantized weight placed on a device mesh: the ``q``/``s`` pair
+    plus the mesh and the ``PartitionSpec`` of its two matrix dims
+    (static pytree data, so it survives the layer scan's slicing).  The
+    Pallas matmul is a Mosaic call that the SPMD partitioner cannot
+    split, so ``ops.quant_matmul`` runs it per shard from this spec."""
+    q: Any
+    s: Any
+    mesh: Any
+    spec: PartitionSpec
+
+    def tree_flatten(self):
+        return (self.q, self.s), (self.mesh, self.spec)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+
+def place_params(params: PyTree, specs: PyTree, mesh) -> PyTree:
+    """Put ``params`` on ``mesh`` with the ``PartitionSpec`` tree
+    ``specs``.  Each quantized leaf comes back as a :class:`PlacedQuant`
+    carrying the spec of its two matrix dims, so every path that runs the
+    weights (fused or eager) runs the Pallas matmul per shard.  Leaves
+    already placed are re-placed, as after a mesh change."""
+
+    def put(leaf, spec):
+        if isinstance(leaf, PlacedQuant):
+            leaf = {"q": leaf.q, "s": leaf.s}
+        if not is_quantized(leaf):
+            return jax.device_put(leaf, NamedSharding(mesh, spec))
+        nd = leaf["q"].ndim
+        ks = (tuple(spec["q"]) + (None,) * nd)[:nd]
+        return PlacedQuant(
+            jax.device_put(leaf["q"], NamedSharding(mesh, spec["q"])),
+            jax.device_put(leaf["s"], NamedSharding(mesh, spec["s"])),
+            mesh, PartitionSpec(*ks[-2:]))
+
+    return jax.tree.map(
+        put, params, specs,
+        is_leaf=lambda l: isinstance(l, PlacedQuant) or is_quantized(l))
 
 
 def dequantize_params(qparams: PyTree) -> PyTree:

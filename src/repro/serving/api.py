@@ -384,10 +384,16 @@ def build_server(config: ServingConfig, cls=None):
             import jax.numpy as jnp
 
             from repro.models import transformer as T
-            params = T.init_params(cfg, jax.random.key(spec.init_seed),
-                                   jnp.float32)
-            srv.register(spec.name, cfg, params, spec.precisions,
-                         predictor=predictor)
+
+            # The f32 master weights exist only to derive the zoo, which
+            # lives in host memory: build both there, so a published-
+            # width tenant never holds f32 and bf16 copies in HBM at once.
+            with jax.default_device(jax.local_devices(backend="cpu")[0]):
+                params = T.init_params(cfg, jax.random.key(spec.init_seed),
+                                       jnp.float32)
+                srv.register(spec.name, cfg, params, spec.precisions,
+                             predictor=predictor)
+            del params
     if config.executor == "sim":
         # Deterministic runs: a background fit must not race the virtual
         # clock, so sim builds wait each fit out at its schedule point.

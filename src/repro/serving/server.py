@@ -11,6 +11,7 @@ loaded (quantized variants run through the fused dequant matmul path).
 from __future__ import annotations
 
 import functools
+import math
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -26,7 +27,8 @@ from repro.core.model_zoo import ModelVariant, ModelZoo
 from repro.core.predictor import RequestPredictor
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
-from repro.quant.quantize import params_nbytes, quantize_params
+from repro.quant.quantize import (params_nbytes, place_params,
+                                  quantize_params)
 
 MB = 1024 * 1024
 
@@ -128,13 +130,11 @@ class TenantRuntime:
 
     def reshard_device_params(self) -> None:
         """Elastic recovery: re-place the resident variant's buffers on
-        the attached mesh (``distributed.elastic.reshard``) after the
-        ledger layout changed.  No-op off-mesh or when nothing is
+        the attached mesh after the ledger layout changed.  No-op off-mesh or when nothing is
         loaded."""
         if self.mesh is None or self.loaded_bits is None:
             return
-        from repro.distributed.elastic import reshard
-        self.device_params = reshard(
+        self.device_params = place_params(
             self.device_params, self._spec_tree(self.loaded_bits),
             self.mesh)
 
@@ -148,10 +148,8 @@ class TenantRuntime:
             return
         host_tree = self.host[variant.bits]
         if self.mesh is not None:
-            from repro.distributed import sharding as SH
-            self.device_params = jax.device_put(
-                host_tree,
-                SH.named(self.mesh, self._spec_tree(variant.bits)))
+            self.device_params = place_params(
+                host_tree, self._spec_tree(variant.bits), self.mesh)
         else:
             self.device_params = jax.tree.map(jnp.asarray, host_tree)
         self.loaded_bits = variant.bits
@@ -388,19 +386,26 @@ class EdgeServer:
     def _attach_physical_mesh(self) -> None:
         """True per-shard placement for real-model tenants: build the
         physical mesh matching the ledger's logical one and route every
-        ``set_variant`` through ``NamedSharding`` device_puts.  Skipped
-        when the process has fewer devices than the mesh asks for (sim
-        builds, plain CPU) — the ledger stays the accounting authority
-        either way."""
+        ``set_variant`` through ``NamedSharding`` device_puts.  On a CPU
+        backend with fewer devices than the mesh asks for (sim builds,
+        CPU tests) placement is skipped and the ledger stays the
+        accounting authority; on an accelerator, real tenants that cannot
+        be placed are an error, never a silent single-device run."""
         shape = self.sharded_mesh
-        n = 1
-        for s in shape:
-            n *= s
+        n = math.prod(shape)
         if jax.device_count() < n:
+            real = [a for a, t in self.tenants.items()
+                    if hasattr(t, "attach_mesh")]
+            if real and jax.default_backend() != "cpu":
+                raise RuntimeError(
+                    f"sharded mesh {shape} needs {n} devices, the "
+                    f"{jax.default_backend()} backend has "
+                    f"{jax.device_count()}: cannot place {real}")
             return
-        from repro.launch.mesh import make_mesh_compat
         dims = (1, shape[0]) if len(shape) == 1 else tuple(shape)
-        self.physical_mesh = make_mesh_compat(dims, ("data", "model"))
+        self.physical_mesh = jax.make_mesh(
+            dims, ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for tr in self.tenants.values():
             if hasattr(tr, "attach_mesh"):
                 tr.attach_mesh(self.physical_mesh)

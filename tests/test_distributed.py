@@ -161,9 +161,10 @@ MULTIDEV = textwrap.dedent("""
     from repro.distributed import checkpoint as ckpt
     from repro.distributed.elastic import reshard, validate_elastic_plan
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh8 = make_mesh_compat((8,), ("data",))
-    mesh24 = make_mesh_compat((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh8 = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+    mesh24 = jax.make_mesh((2, 4), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
 
     # 1. compressed all-reduce ~= exact all-reduce
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 128)),

@@ -291,14 +291,15 @@ def test_set_variant_places_real_shards_matching_ledger_fractions():
     import jax.numpy as jnp
 
     from repro.configs import get_config
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     from repro.models import transformer as T
     from repro.serving.server import TenantRuntime
 
     cfg = get_config("tinyllama-1.1b", reduced=True)
     params = T.init_params(cfg, jax.random.key(0), jnp.float32)
     tr = TenantRuntime("tinyllama-1.1b", cfg, params, precisions=(16, 8))
-    mesh = make_mesh_compat((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     tr.attach_mesh(mesh)
     frac = SH.weight_shard_fraction(
         cfg, SH.LogicalMesh({"data": 1, "model": 8}))

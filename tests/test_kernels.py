@@ -157,6 +157,58 @@ def test_quant_matmul_batched_lhs():
                                rtol=2e-4, atol=2e-4)
 
 
+def test_quant_matmul_under_jit():
+    """The served path calls the kernel inside ``jax.jit``, where the
+    leading dims are traced: sizing the row block must stay static."""
+    x = rand(2, 11, 256)
+    w = rand(256, 200, key=jax.random.key(3))
+    wq, sc = ref.quantize_weights(w, bits=8, group=32)
+    got = jax.jit(lambda x, w, s: qm.quant_matmul(x, w, s, interpret=True)
+                  )(x, wq, sc)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.quant_matmul(x, wq, sc)),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("spec", [(None, "model"), ("model", None),
+                                  (None, None)])
+def test_quant_matmul_per_shard(spec):
+    """On a mesh the dispatcher runs the kernel per shard: an N-sharded
+    weight concatenates, a K-sharded one sums partial products."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels import ops
+
+    n = jax.device_count()
+    mesh = jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    x = rand(3, 64 * n)
+    w = rand(64 * n, 32 * n, key=jax.random.key(4))
+    wq, sc = ref.quantize_weights(w, bits=8, group=32)
+    wq = jax.device_put(wq, NamedSharding(mesh, P(*spec)))
+    got = jax.jit(lambda x, w, s: ops.quant_matmul(
+        x, w, s, impl="pallas", mesh=mesh, spec=spec))(x, wq, sc)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.quant_matmul(x, wq, sc)),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_quant_matmul_per_shard_rejects_split_scale_groups():
+    """A K-sharded weight whose scale groups do not divide over the mesh
+    axis is an error, never a silent gather of the whole weight."""
+    from jax.sharding import AbstractMesh
+
+    from repro.kernels import ops
+
+    mesh = AbstractMesh((1, 4), ("data", "model"))
+    x = rand(3, 192)
+    wq, sc = ref.quantize_weights(rand(192, 64, key=jax.random.key(4)),
+                                  bits=8, group=32)  # 6 groups over 4
+    with pytest.raises(ValueError, match="scale groups"):
+        ops.quant_matmul(x, wq, sc, impl="pallas", mesh=mesh,
+                         spec=("model", None))
+
+
 def test_quantize_roundtrip_error_bounded():
     w = rand(256, 128, key=jax.random.key(11))
     for bits, bound in ((8, 0.02), (4, 0.35)):
@@ -194,6 +246,25 @@ def test_ssd_scan_shapes(B, S, H, P, G, N, chunk):
     np.testing.assert_allclose(np.asarray(got_p), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(pstate), np.asarray(wstate),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_ssd_scan_under_jit():
+    """``ssd_scan`` inside ``jax.jit`` (as prefill runs it), across a
+    ragged chunk boundary, with the state carried out."""
+    B, S, H, P, G, N = 1, 40, 4, 16, 2, 8
+    ks = jax.random.split(jax.random.key(21), 6)
+    args = (rand(B, S, H, P, key=ks[0], scale=0.5),
+            jax.nn.softplus(rand(B, S, H, key=ks[1])),
+            -jnp.exp(rand(H, key=ks[2], scale=0.5)),
+            rand(B, S, G, N, key=ks[3], scale=0.3),
+            rand(B, S, G, N, key=ks[4], scale=0.3), rand(H, key=ks[5]))
+    got, gstate = jax.jit(lambda *a: ssd.ssd_scan(
+        *a, chunk=16, return_state=True, interpret=True))(*args)
+    want, wstate = ref.ssd_scan(*args, return_state=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(gstate), np.asarray(wstate),
                                rtol=2e-4, atol=2e-4)
 
 

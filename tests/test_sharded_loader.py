@@ -7,6 +7,8 @@ devices) real-mesh shard placement matching the accounting fractions.
 Synthetic-zoo tests drive the manager + channel directly (no models);
 engine tests build through the declarative API with sim executors.
 """
+import math
+
 import jax
 import numpy as np
 import pytest
@@ -320,14 +322,15 @@ def test_real_mesh_placement_matches_ledger_fractions():
     figure the per-device ledger budgets with."""
     import jax.numpy as jnp
 
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     from repro.models import transformer as T
 
     cfg = get_config("tinyllama-1.1b", reduced=True)
     params = jax.tree.map(
         lambda x: x.astype(jnp.bfloat16),
         T.init_params(cfg, jax.random.key(0), jnp.float32))
-    mesh = make_mesh_compat((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     specs = SH.param_specs(cfg, params, mesh, fsdp=False)
     placed = jax.device_put(params, SH.named(mesh, specs))
     per_device = {d.id: 0 for d in mesh.devices.flatten()}
@@ -341,3 +344,58 @@ def test_real_mesh_placement_matches_ledger_fractions():
     for dev, nbytes in per_device.items():
         assert nbytes / total == pytest.approx(frac, rel=1e-6), \
             (dev, nbytes, total, frac)
+
+
+def test_real_tenants_without_enough_accelerators_raise(monkeypatch):
+    """On an accelerator, real tenants that cannot be placed on the
+    requested mesh stop the build; only a CPU backend keeps the skip
+    (the logical mesh still drives the accounting there)."""
+    cfg = ServingConfig(tenants=(TenantSpec("tinyllama-1.1b"),),
+                        loader=LoaderSpec(sharded=True,
+                                          mesh_shape=(jax.device_count()
+                                                      + 1,)),
+                        executor="real")
+    srv = EdgeServer.build(cfg)  # CPU: placement skipped
+    assert srv.physical_mesh is None
+    srv.close()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="cannot place"):
+        EdgeServer.build(cfg)
+
+
+def test_sharded_int8_serving_runs_kernel_per_shard():
+    """A real int8 tenant served from a mesh over local devices runs
+    the Pallas matmul per shard (``PlacedQuant`` → ``shard_map``) and
+    decodes the same tokens as its weights on one device, on the fused
+    path and on the eager path that batches with extra inputs take."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    from repro.serving.server import _generate_tokens
+
+    # The reduced model's row-parallel ``wo`` (K=64) holds two scale
+    # groups, so at most two shards keep whole groups.
+    n = math.gcd(jax.device_count(), 2)
+    srv = EdgeServer.build(ServingConfig(
+        tenants=(TenantSpec("tinyllama-1.1b", precisions=(8,)),),
+        loader=LoaderSpec(sharded=True, mesh_shape=(n,)),
+        kv_headroom_shape=(1, 10), executor="real"))
+    tr = srv.tenants["tinyllama-1.1b"]
+    assert srv.physical_mesh is not None and tr.mesh is srv.physical_mesh
+    prompt = (np.arange(7, dtype=np.int32) * 5 % tr.cfg.vocab_size)[None]
+    ops.set_impl("pallas")  # interpret mode on CPU
+    try:
+        r = srv.serve("tinyllama-1.1b", prompt, max_new=3, now_ms=0.0)
+        # A text model ignores the stub vision input; it only routes the
+        # batch through the eager prefill/decode path.
+        eager = tr.generate(prompt, 3, extra={"patch_embeds": np.zeros(
+            (1, 1, tr.cfg.d_model), np.float32)})
+        one = jax.device_put(tr.host[8], jax.devices()[0])
+        want = _generate_tokens(tr.cfg, one, jnp.asarray(prompt),
+                                max_new=3, max_len=10)
+    finally:
+        ops.set_impl(None)
+        srv.close()
+    assert not r.failed and r.bits == 8
+    np.testing.assert_array_equal(r.tokens, np.asarray(want))
+    np.testing.assert_array_equal(eager, np.asarray(want))
