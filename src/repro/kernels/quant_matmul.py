@@ -111,5 +111,6 @@ def quant_matmul(
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(x2, w_q, scales)
     return out[:M, :N].reshape(*lead, N)

@@ -151,6 +151,7 @@ def ssd_scan(
             jax.ShapeDtypeStruct((Bb, H, N, P), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_scan",
     )(D.astype(jnp.float32), xt,
       dtf[:, :, :, None, :], a_cum[:, :, :, None, :], a_cum[..., None],
       btt, ct, init_t)

@@ -208,7 +208,6 @@ class ServingConfig:
     loader: LoaderSpec = field(default_factory=LoaderSpec)
     predictor: PredictorSpec = field(default_factory=PredictorSpec)
     executor: str = "real"  # "real" | "sim"
-    straggler_deadline_s: float = 30.0
     # Chip-fault schedule (elastic mesh): chip-down/chip-up events on the
     # engine clock, each down firing one transactional drain plan.
     # Requires LoaderSpec(sharded=True) — the drain planner works the
@@ -348,7 +347,6 @@ def build_server(config: ServingConfig, cls=None):
               delta_ms=config.delta_ms,
               adaptive_delta=config.adaptive_delta,
               history_ms=config.history_ms,
-              straggler_deadline_s=config.straggler_deadline_s,
               max_batch=config.batching.max_batch,
               batch_window_ms=config.batching.window_ms,
               continuous=config.batching.continuous,

@@ -25,6 +25,8 @@ class Request:
     # leaked ids across server builds in one process, making the FIFO
     # rid tie-break in next_batch non-reproducible between builds).
     rid: Optional[int] = None
+    # perf_counter_ns when the engine took it in (its queue-wait span).
+    submit_ns: int = 0
 
 
 @dataclass

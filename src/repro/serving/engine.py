@@ -54,6 +54,7 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serving.batcher import Batch, Batcher, Request
 from repro.serving.stats import AuditEvent, EventKind, ServingStats
+from repro.serving.tracing import Tracer
 
 MB = 1024 * 1024
 
@@ -229,7 +230,8 @@ class ServingEngine:
                  loader: Optional[LoaderChannel] = None,
                  continuous: bool = False,
                  audit: str = "full",
-                 scheduler: str = "indexed"):
+                 scheduler: str = "indexed",
+                 tracer: Optional[Tracer] = None):
         if audit not in ("full", "counters"):
             raise ValueError(
                 f"audit must be 'full' or 'counters', got {audit!r}")
@@ -256,6 +258,8 @@ class ServingEngine:
         # tests/test_engine_equivalence.py.
         self.scheduler = scheduler
         self.indexed = scheduler == "indexed"
+        # The host's span and counter recorder (EdgeServer.tracer).
+        self.tracer = tracer or Tracer(spans=False)
         # Continuous batching: the admission unit is the request, not the
         # batch — requests join/leave the running decode per step and
         # charge/free page-granular KV (requires a KVPagePool on the
@@ -353,6 +357,7 @@ class ServingEngine:
     def submit(self, req: Request, now_ms: float) -> None:
         """Enqueue a request; feeds the tenant's RNN arrival predictor."""
         req.arrival_ms = now_ms if req.arrival_ms == 0.0 else req.arrival_ms
+        req.submit_ns = time.perf_counter_ns()
         self._maint_clean = False  # new arrival: predictions shift
         self.host.tenants[req.app].predictor.observe_request(
             req.arrival_ms)
@@ -398,9 +403,12 @@ class ServingEngine:
                 self.loader.cancel(batch.app, now_ms)
         staged = (self.loader.peek_use(batch.app)
                   if self.loader is not None else None)
-        adm: BatchAdmission = mgr.admit_batch(
-            batch.app, now_ms, kv_mb,
-            demand_cold=staged.demand if staged is not None else False)
+        tracer = self.tracer
+        rids = [r.rid for r in batch.requests]
+        with tracer.span("manager.admit", tenant=batch.app, rids=rids):
+            adm: BatchAdmission = mgr.admit_batch(
+                batch.app, now_ms, kv_mb,
+                demand_cold=staged.demand if staged is not None else False)
         if adm.self_downgraded:
             self.kv_downgrades += 1
         if adm.failed:
@@ -433,13 +441,17 @@ class ServingEngine:
         load_pen_ms = (tr.zoo.by_bits(adm.bits).load_ms
                        if sync_cold and not adm.warm else 0.0)
         self._event(now_ms, "admit", batch.app, adm.kv_mb)
+        tracer.count("engine.batches")
+        tracer.count("engine.batch_rows", len(batch.requests))
         t0 = time.monotonic()
         virtual_ms: Optional[float] = None
         try:
-            if self._executor is not None:  # legacy injected callable
-                tokens = self._executor(tr, batch, extra)
-            else:  # TenantExecutor protocol: tokens + optional sim time
-                tokens, virtual_ms = tr.execute(batch, extra)
+            with tracer.span("executor.run", tenant=batch.app, rids=rids,
+                             bits=adm.bits):
+                if self._executor is not None:  # legacy injected callable
+                    tokens = self._executor(tr, batch, extra)
+                else:  # TenantExecutor protocol: tokens + optional time
+                    tokens, virtual_ms = tr.execute(batch, extra)
         except BaseException:
             # Execution crashed (XLA OOM, bad inputs): release the cache
             # charge so it doesn't leak, balance the audit trail, and
@@ -500,6 +512,12 @@ class ServingEngine:
         the load commits, while everyone else keeps prefilling/decoding.
         If no variant fits, the batch is admitted anyway so the failure
         is counted the normal way."""
+        if not self.batcher.queues:
+            return  # nothing queued: nothing to stage
+        with self.tracer.span("engine.stage_demand"):
+            self._stage_demand(now)
+
+    def _stage_demand(self, now: float) -> None:
         mgr = self.host.manager
         # queued_apps() is a live keys view (no per-step copy); nothing
         # in this loop inserts or drops queue keys, so iterating it
@@ -558,6 +576,18 @@ class ServingEngine:
                 self.loader.execute(
                     RA.ResidencyPlan(RA.procure_actions(plan, staged=True)),
                     now, demand=True)
+
+    def _dequeued(self, batch: Batch) -> None:
+        """Each request's wait in the queue, submit to dequeue, as one
+        ``engine.queue`` span (memory only: it ended before it could be
+        announced to a profiler)."""
+        tracer = self.tracer
+        if not tracer.recording:
+            return
+        now = time.perf_counter_ns()
+        for r in batch.requests:
+            tracer.record("engine.queue", r.submit_ns, now,
+                          tenant=batch.app, rid=r.rid)
 
     def _note_span(self, t0: float, t1: float, app: str) -> None:
         """Record one retired execution span; on the indexed path, also
@@ -653,58 +683,65 @@ class ServingEngine:
         pending = sorted(requests, key=lambda r: r.arrival_ms)
         i, n, now = 0, len(pending), 0.0
         while i < n or self.batcher.pending():
-            if not self.batcher.pending():
-                t_next = pending[i].arrival_ms if i < n else math.inf
-                if self.loader is not None:
-                    # Idle wake-ups: a pending load commit, or a tenant's
-                    # prefetch trigger (t_pred − Δ − θ) — sleeping past
-                    # either would turn a hideable load into a stall.
-                    t_next = min(t_next, self.loader.earliest_ready(),
-                                 self.host.next_prefetch_trigger(now))
-                if self.elastic is not None:
-                    # A scheduled chip fault wakes the loop even when it
-                    # is otherwise idle — drains fire at their instant.
-                    t_next = min(t_next, self.elastic.next_event_ms())
-                now = max(now, t_next)
-            while i < n and pending[i].arrival_ms <= now:
-                self.submit(pending[i], pending[i].arrival_ms)
-                i += 1
-            # Hold a short batch for an imminent arrival (amortization).
-            if (self.batcher.pending() < self.max_batch and i < n
-                    and pending[i].arrival_ms <= now + self.batch_window_ms):
-                now = pending[i].arrival_ms
-                continue
-            if self.loader is not None:
-                self._reap_loads(now)
-                if self.elastic is not None:
-                    self._now = now
-                    self.elastic.poll(now)
-                self.host.predict_and_preload(now)
-                self._stage_demand_loads(now)
-                batch = self.batcher.next_batch(
-                    exclude=self.loader.inflight)
-                if batch is None:
-                    # Every queued tenant is awaiting its own load (or
-                    # nothing is queued at all): jump to the earliest
-                    # commit or the next arrival — the loop idles, it
-                    # does not block on a transfer.
-                    t_next = self.loader.earliest_ready()
-                    if i < n:
-                        t_next = min(t_next, pending[i].arrival_ms)
+            with self.tracer.span("engine.advance"):
+                if not self.batcher.pending():
+                    t_next = pending[i].arrival_ms if i < n else math.inf
+                    if self.loader is not None:
+                        # Idle wake-ups: a pending load commit, or a
+                        # tenant's prefetch trigger (t_pred − Δ − θ) —
+                        # sleeping past either would turn a hideable load
+                        # into a stall.
+                        t_next = min(t_next, self.loader.earliest_ready(),
+                                     self.host.next_prefetch_trigger(now))
                     if self.elastic is not None:
-                        t_next = min(t_next,
-                                     self.elastic.next_event_ms())
-                    if t_next is not math.inf:
-                        now = max(now, t_next)
-                        continue
-                    break
-            else:
-                batch = self.batcher.next_batch()
-            t0 = now
-            _, service_ms, _ = self.execute_batch(
-                batch, now, charge_load=self.loader is None)
-            now += service_ms
-            self._note_span(t0, now, batch.app)
+                        # A scheduled chip fault wakes the loop even when
+                        # it is otherwise idle — drains fire at their
+                        # instant.
+                        t_next = min(t_next, self.elastic.next_event_ms())
+                    now = max(now, t_next)
+                while i < n and pending[i].arrival_ms <= now:
+                    self.submit(pending[i], pending[i].arrival_ms)
+                    i += 1
+                # Hold a short batch for an imminent arrival
+                # (amortization).
+                if (self.batcher.pending() < self.max_batch and i < n
+                        and pending[i].arrival_ms
+                        <= now + self.batch_window_ms):
+                    now = pending[i].arrival_ms
+                    continue
+                if self.loader is not None:
+                    with self.tracer.span("engine.maintain"):
+                        self._reap_loads(now)
+                        if self.elastic is not None:
+                            self._now = now
+                            self.elastic.poll(now)
+                        self.host.predict_and_preload(now)
+                        self._stage_demand_loads(now)
+                    batch = self.batcher.next_batch(
+                        exclude=self.loader.inflight)
+                    if batch is None:
+                        # Every queued tenant is awaiting its own load (or
+                        # nothing is queued at all): jump to the earliest
+                        # commit or the next arrival — the loop idles, it
+                        # does not block on a transfer.
+                        t_next = self.loader.earliest_ready()
+                        if i < n:
+                            t_next = min(t_next, pending[i].arrival_ms)
+                        if self.elastic is not None:
+                            t_next = min(t_next,
+                                         self.elastic.next_event_ms())
+                        if t_next is not math.inf:
+                            now = max(now, t_next)
+                            continue
+                        break
+                else:
+                    batch = self.batcher.next_batch()
+                self._dequeued(batch)
+                t0 = now
+                _, service_ms, _ = self.execute_batch(
+                    batch, now, charge_load=self.loader is None)
+                now += service_ms
+                self._note_span(t0, now, batch.app)
         if self.loader is not None:
             # Trace drained: commit whatever is still staging so the
             # audit trail balances and residency reflects the weights.
@@ -747,50 +784,54 @@ class ServingEngine:
         self._wire_audit()
         now = self._cluster_now
         while True:
-            if not self.batcher.pending():
-                t_next = math.inf
-                if self.loader is not None:
-                    t_next = min(self.loader.earliest_ready(),
-                                 self.host.next_prefetch_trigger(now))
-                if self.elastic is not None:
-                    t_next = min(t_next, self.elastic.next_event_ms())
-                if not t_next < horizon_ms:
-                    break
-                now = max(now, t_next)
-            elif not now < horizon_ms:
-                t_next = now  # runnable work at/after the horizon
-                break
-            if self.loader is not None:
-                self._reap_loads(now)
-            if self.elastic is not None:
-                self._now = now
-                self.elastic.poll(now)
-            if self.loader is not None:
-                self.host.predict_and_preload(now)
-                self._stage_demand_loads(now)
-                batch = self.batcher.next_batch(
-                    exclude=self.loader.inflight)
-            else:
-                batch = self.batcher.next_batch()
-            if batch is None:
+            with self.tracer.span("engine.advance"):
                 if not self.batcher.pending():
-                    continue  # maintenance consumed the wake-up;
-                    # recompute the idle candidates from the top
-                # Every queued tenant is awaiting its own load.
-                t_next = math.inf
-                if self.loader is not None:
-                    t_next = self.loader.earliest_ready()
-                if self.elastic is not None:
-                    t_next = min(t_next, self.elastic.next_event_ms())
-                if not t_next < horizon_ms:
+                    t_next = math.inf
+                    if self.loader is not None:
+                        t_next = min(self.loader.earliest_ready(),
+                                     self.host.next_prefetch_trigger(now))
+                    if self.elastic is not None:
+                        t_next = min(t_next, self.elastic.next_event_ms())
+                    if not t_next < horizon_ms:
+                        break
+                    now = max(now, t_next)
+                elif not now < horizon_ms:
+                    t_next = now  # runnable work at/after the horizon
                     break
-                now = max(now, t_next)
-                continue
-            t0 = now
-            _, service_ms, _ = self.execute_batch(
-                batch, now, charge_load=self.loader is None)
-            now += service_ms
-            self._note_span(t0, now, batch.app)
+                with self.tracer.span("engine.maintain"):
+                    if self.loader is not None:
+                        self._reap_loads(now)
+                    if self.elastic is not None:
+                        self._now = now
+                        self.elastic.poll(now)
+                    if self.loader is not None:
+                        self.host.predict_and_preload(now)
+                        self._stage_demand_loads(now)
+                if self.loader is not None:
+                    batch = self.batcher.next_batch(
+                        exclude=self.loader.inflight)
+                else:
+                    batch = self.batcher.next_batch()
+                if batch is None:
+                    if not self.batcher.pending():
+                        continue  # maintenance consumed the wake-up;
+                        # recompute the idle candidates from the top
+                    # Every queued tenant is awaiting its own load.
+                    t_next = math.inf
+                    if self.loader is not None:
+                        t_next = self.loader.earliest_ready()
+                    if self.elastic is not None:
+                        t_next = min(t_next, self.elastic.next_event_ms())
+                    if not t_next < horizon_ms:
+                        break
+                    now = max(now, t_next)
+                    continue
+                self._dequeued(batch)
+                t0 = now
+                _, service_ms, _ = self.execute_batch(
+                    batch, now, charge_load=self.loader is None)
+                now += service_ms
+                self._note_span(t0, now, batch.app)
         self._cluster_now = now
         return t_next
 

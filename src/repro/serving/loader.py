@@ -71,6 +71,7 @@ from repro.core.model_zoo import ModelVariant
 from repro.core.policies import ProcurePlan
 from repro.distributed.compression import wire_compression_ratio
 from repro.serving.events import MonotoneQueue
+from repro.serving.tracing import Tracer
 
 INF = math.inf
 
@@ -172,6 +173,7 @@ class BackgroundLoader:
         self.manager = manager
         self.compress = compress
         self._stage_fn = stage_fn or (lambda app, variant: None)
+        self.tracer = Tracer(spans=False)  # the server's, from start()
         self._pool = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="model-loader")
         # Predictor fits get their own worker: they mutate no device
@@ -232,11 +234,20 @@ class BackgroundLoader:
     def stage(self, app: str, variant: Optional[ModelVariant]) -> Future:
         """Queue a physical weight move on the single worker.  All device
         mutations go through here so they serialize in submission order."""
-        return self._pool.submit(self._stage_fn, app, variant)
+        return self._pool.submit(self._staged, app, variant,
+                                 self.tracer.current())
+
+    def _staged(self, app: str, variant: Optional[ModelVariant],
+                parent: int) -> None:
+        with self.tracer.span("loader.stage", parent=parent, tenant=app,
+                              bits=variant.bits if variant else None):
+            self._stage_fn(app, variant)
 
     def stage_sync(self, app: str, variant: Optional[ModelVariant]) -> None:
         """Hot-path (admission) staging: same channel, but wait for it."""
-        self.stage(app, variant).result()
+        fut = self.stage(app, variant)
+        with self.tracer.span("loader.wait", tenant=app):
+            fut.result()
 
     def submit_fit(self, predictor,
                    steps: Optional[int] = None) -> Optional[Future]:
@@ -254,10 +265,15 @@ class BackgroundLoader:
             return None
         if steps is None:
             steps = getattr(predictor, "fit_steps", 150)
-        fut = self._fit_pool.submit(predictor.fit, steps)
+        fut = self._fit_pool.submit(self._fit, predictor, steps,
+                                    self.tracer.current())
         self._fits[key] = fut
         self.fits_scheduled += 1
         return fut
+
+    def _fit(self, predictor, steps: int, parent: int) -> float:
+        with self.tracer.span("predictor.fit", parent=parent):
+            return predictor.fit(steps)
 
     def close(self) -> None:
         self._pool.shutdown(wait=True)
@@ -393,7 +409,8 @@ class BackgroundLoader:
             ld = self.inflight.pop(app)
             if not ld.staging:
                 continue  # a stale record cannot commit twice
-            ld.future.result()
+            with self.tracer.span("loader.wait", tenant=app):
+                ld.future.result()
             commit = A.Load(app, ld.variant, claim_mb=ld.charge_mb)
             state.apply(A.ResidencyPlan((commit,)))
             ld.state = "committed"

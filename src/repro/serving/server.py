@@ -29,6 +29,7 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.quant.quantize import (params_nbytes, place_params,
                                   quantize_params)
+from repro.serving.tracing import Tracer
 
 MB = 1024 * 1024
 
@@ -40,9 +41,10 @@ def _generate_tokens(cfg: ModelConfig, params, prompts: jnp.ndarray, *,
     steps in one XLA program (cache shapes are static — prefill pads to
     ``max_len``), so serving cost is one dispatch per batch instead of
     hundreds of eager ops per token."""
-    logits, cache = T.prefill(cfg, params, {"tokens": prompts},
-                              max_len=max_len)
-    tok = T.greedy_token(cfg, logits)
+    with jax.named_scope("prefill"):
+        logits, cache = T.prefill(cfg, params, {"tokens": prompts},
+                                  max_len=max_len)
+        tok = T.greedy_token(cfg, logits)
 
     def step(carry, _):
         prev, c = carry
@@ -55,7 +57,9 @@ def _generate_tokens(cfg: ModelConfig, params, prompts: jnp.ndarray, *,
 
     if max_new == 1:
         return tok[:, None]
-    _, rest = jax.lax.scan(step, (tok, cache), None, length=max_new - 1)
+    with jax.named_scope("decode"):
+        _, rest = jax.lax.scan(step, (tok, cache), None,
+                               length=max_new - 1)
     return jnp.concatenate([tok[:, None], jnp.moveaxis(rest, 0, 1)],
                            axis=1)
 
@@ -68,7 +72,6 @@ class ServeResult:
     failed: bool
     bits: Optional[int]
     latency_s: float
-    redispatched: bool = False
 
 
 class TenantRuntime:
@@ -101,6 +104,7 @@ class TenantRuntime:
         self.device_params: Optional[Any] = None
         self.loaded_bits: Optional[int] = None
         self.predictor = predictor or RequestPredictor(context=8, hidden=16)
+        self.tracer = Tracer(spans=False)  # the server's, from start()
         self._decode = None  # jitted per (bits)
         # Physical placement (sharded mesh): when a mesh is attached,
         # set_variant device_puts each leaf with a NamedSharding from
@@ -147,12 +151,20 @@ class TenantRuntime:
         if variant.bits == self.loaded_bits:
             return
         host_tree = self.host[variant.bits]
+        t0 = time.perf_counter_ns()
         if self.mesh is not None:
             self.device_params = place_params(
                 host_tree, self._spec_tree(variant.bits), self.mesh)
         else:
             self.device_params = jax.tree.map(jnp.asarray, host_tree)
         self.loaded_bits = variant.bits
+        # The copies run on after this returns: loader.h2d ends when the
+        # new buffers are ready, measured off this thread.
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(host_tree))
+        self.tracer.count("loader.h2d_bytes", nbytes)
+        self.tracer.span_until_ready("loader.h2d", t0, self.device_params,
+                                     tenant=self.name, bits=variant.bits,
+                                     bytes=nbytes)
 
     def generate(self, prompts: np.ndarray, max_new: int,
                  extra: Optional[dict] = None) -> np.ndarray:
@@ -167,9 +179,13 @@ class TenantRuntime:
         cfg, params = self.cfg, self.device_params
         S = prompts.shape[1]
         if not extra:
-            return np.asarray(_generate_tokens(
-                cfg, params, jnp.asarray(prompts), max_new=max_new,
-                max_len=S + max_new))
+            with self.tracer.span("executor.dispatch"):
+                out = _generate_tokens(cfg, params, jnp.asarray(prompts),
+                                       max_new=max_new, max_len=S + max_new)
+            # The fetch holds any wait for the device, a weight copy
+            # still in flight included.
+            with self.tracer.span("executor.fetch"):
+                return np.asarray(out)
         batch = {"tokens": jnp.asarray(prompts)}
         batch.update({k: jnp.asarray(v) for k, v in extra.items()})
         logits, cache = T.prefill(cfg, params, batch, max_len=S + max_new)
@@ -203,8 +219,8 @@ class EdgeServer:
     """
 
     def __init__(self, budget_mb: float, policy="iws-bfe",
-                 delta_ms: float = 500.0, straggler_deadline_s: float = 30.0,
-                 max_batch: int = 8, batch_window_ms: float = 0.0,
+                 delta_ms: float = 500.0, max_batch: int = 8,
+                 batch_window_ms: float = 0.0,
                  prefetch: bool = True, history_ms: float = 3000.0,
                  fallback="desperation",
                  sharded_mesh: Optional[Tuple[int, ...]] = None,
@@ -260,6 +276,9 @@ class EdgeServer:
         # pass re-materializes full arrival history on every call).
         self.audit = audit
         self.scheduler = scheduler
+        # Spans and counters of the serving path (repro.serving.tracing):
+        # spans at the "full" audit level, counters only at "counters".
+        self.tracer = Tracer(spans=audit == "full")
         self._tpred_memo: Dict[str, Tuple[tuple, float]] = {}
         # Horizon before which a repeat of the last maintenance pass is
         # provably the identical no-op (every tenant took the indexed
@@ -274,8 +293,6 @@ class EdgeServer:
         self.prefetch = prefetch
         self.max_batch = max_batch
         self.batch_window_ms = batch_window_ms
-        self.straggler_deadline_s = straggler_deadline_s
-        self.redispatch_count = 0
         self.results: List[ServeResult] = []
         # Sim-executor builds set this: background fits complete before
         # the next prediction so virtual-time runs stay bit-deterministic
@@ -321,18 +338,29 @@ class EdgeServer:
         from repro.serving.loader import BackgroundLoader
 
         zoos = {n: t.zoo for n, t in self.tenants.items()}
+        tracer = self.tracer
+        for t in self.tenants.values():
+            if hasattr(t, "tracer"):
+                t.tracer = tracer
 
         def stage(app: str, variant: Optional[ModelVariant]) -> None:
-            self.tenants[app].set_variant(variant)
+            tr = self.tenants[app]
+            before = getattr(tr, "loaded_bits", None)
+            tr.set_variant(variant)
+            after = getattr(tr, "loaded_bits", None)
+            if after != before:
+                tracer.count(f"manager.moves.{_move_kind(before, after)}")
 
         def loader_cb(app: str, variant: Optional[ModelVariant]) -> None:
             # Synchronous (admission-path) weight moves ride the same
             # single-worker staging channel as background loads, so
             # device mutations land in the order their accounting did.
-            if self.loader is not None:
-                self.loader.stage_sync(app, variant)
-            else:
-                stage(app, variant)
+            with tracer.span("manager.enact", tenant=app,
+                             bits=variant.bits if variant else None):
+                if self.loader is not None:
+                    self.loader.stage_sync(app, variant)
+                else:
+                    stage(app, variant)
 
         self.manager = EdgeMultiAI(
             zoos, self.budget_mb, policy=self.policy,
@@ -358,6 +386,7 @@ class EdgeServer:
                                             compress=self.compress)
                            if self.prefetch else None)
         if self.loader is not None:
+            self.loader.tracer = tracer
             # Admission-path migrations land in the same audit trail as
             # loader-path ones (the engine mirrors loader events).
             self.manager.on_migrate = (
@@ -369,7 +398,7 @@ class EdgeServer:
             self, max_batch=self.max_batch,
             batch_window_ms=self.batch_window_ms, loader=self.loader,
             continuous=self.continuous, audit=self.audit,
-            scheduler=self.scheduler)
+            scheduler=self.scheduler, tracer=tracer)
         if self.fault is not None:
             from repro.serving.elastic import ElasticController
             ctrl = ElasticController(self.fault, self.manager,
@@ -485,9 +514,11 @@ class EdgeServer:
                 v.size_mb, n, fracs[app]))
 
     def close(self) -> None:
-        """Drain and shut down the background staging worker."""
+        """Drain and shut down the background staging worker; what the
+        tracer recorded stays readable."""
         if self.loader is not None:
             self.loader.close()
+        self.tracer.close()
 
     # ------------------------------------------------------------------
     def _predict_time(self, name: str, predictor) -> float:
@@ -499,13 +530,15 @@ class EdgeServer:
         while skipping the O(history) forward pass the linear path runs
         once per tenant per maintenance pass."""
         if self.scheduler != "indexed":
-            return predictor.predict_next_time()
+            with self.tracer.span("predictor.predict", tenant=name):
+                return predictor.predict_next_time()
         key = (len(predictor.history), predictor.fits,
                predictor.last_time)
         hit = self._tpred_memo.get(name)
         if hit is not None and hit[0] == key:
             return hit[1]
-        t = predictor.predict_next_time()
+        with self.tracer.span("predictor.predict", tenant=name):
+            t = predictor.predict_next_time()
         self._tpred_memo[name] = (key, t)
         return t
 
@@ -616,9 +649,10 @@ class EdgeServer:
             all_skipped = False
             free_mb = None
             if self.loader is not None and tr.predictor.fit_due():
-                fut = self.loader.submit_fit(tr.predictor)
-                if fut is not None and self.sync_predictor_fits:
-                    fut.result()  # lands at this exact virtual instant
+                with self.tracer.span("predictor.fit_submit", tenant=name):
+                    fut = self.loader.submit_fit(tr.predictor)
+                    if fut is not None and self.sync_predictor_fits:
+                        fut.result()  # lands at this exact virtual instant
             t_pred = self._predict_time(name, tr.predictor)
             self.manager.set_prediction(name, t_pred)
             theta = tr.zoo.largest.load_ms
@@ -641,20 +675,23 @@ class EdgeServer:
                     # (the engine stages it and admits the batch cold);
                     # calling it a prefetch would count a request that
                     # waited out the transfer as a warm start.
-                    plan = self.manager.plan_prefetch(name, now_ms)
-                    if plan is not None:
-                        self.loader.execute(
-                            RA.ResidencyPlan(
-                                RA.procure_actions(plan, staged=True)),
-                            now_ms, predicted_ms=t_pred)
+                    with self.tracer.span("manager.plan_prefetch",
+                                          tenant=name):
+                        plan = self.manager.plan_prefetch(name, now_ms)
+                        if plan is not None:
+                            self.loader.execute(
+                                RA.ResidencyPlan(
+                                    RA.procure_actions(plan, staged=True)),
+                                now_ms, predicted_ms=t_pred)
         self.maint_valid_ms = valid if all_skipped else float("-inf")
         if (self.loader is not None and self.engine is not None
                 and self.loader.inflight):  # nothing staged: no-op
             # Per-tenant Δ so staleness agrees with the (possibly
             # adaptive) window that justified the prefetch.
-            self.loader.cancel_stale(
-                now_ms, self.manager.delta_for,
-                has_queued=lambda a: self.engine.batcher.queued(a) > 0)
+            with self.tracer.span("loader.cancel_stale"):
+                self.loader.cancel_stale(
+                    now_ms, self.manager.delta_for,
+                    has_queued=lambda a: self.engine.batcher.queued(a) > 0)
 
     def next_prefetch_trigger(self, now_ms: float) -> float:
         """Earliest *future* t_pred − Δ − θ across tenants that could use
@@ -701,16 +738,8 @@ class EdgeServer:
             return self._record(ServeResult(
                 app, np.zeros((len(prompts), 0), np.int32), warm, True,
                 None, service_ms / 1e3))
-        elapsed = service_ms / 1e3
-        redis = False
-        if elapsed > self.straggler_deadline_s:
-            # Straggler mitigation: on a real fleet this re-dispatches to
-            # the replica pod (the multi-pod mesh's second pod); here we
-            # count and serve locally.
-            self.redispatch_count += 1
-            redis = True
         return self._record(ServeResult(
-            app, toks, warm, False, tr.loaded_bits, elapsed, redis))
+            app, toks, warm, False, tr.loaded_bits, service_ms / 1e3))
 
     def _record(self, r: ServeResult) -> ServeResult:
         self.results.append(r)
@@ -719,8 +748,8 @@ class EdgeServer:
     # ------------------------------------------------------------------
     def stats(self) -> "ServingStats":
         """The engine's typed :class:`~repro.serving.stats.ServingStats`
-        with the server-level gauges filled in (residency, latency,
-        redispatch, predictor fits, adaptive windows, device ledger).
+        with the server-level gauges filled in (residency, predictor
+        fits, adaptive windows, device ledger).
         All request counts are per *request* (the engine's unit), so the
         top-level ratios and the per-tenant breakdown describe the same
         population — a multi-row serve() batch counts once per row."""
@@ -732,17 +761,13 @@ class EdgeServer:
         if not eng_results:  # serve() always routes through the engine
             return ServingStats()
         n = len(eng_results)
-        ok = [r.latency_ms for r in eng_results if not r.failed]
         extra: dict = {
-            "redispatched": self.redispatch_count,
             "resident_mb": self.manager.state.used_mb,
             "weights_mb": self.manager.state.weights_mb,
             "kv_mb": self.manager.state.kv_mb,
             "requests": n,
             "warm_ratio": sum(r.warm for r in eng_results) / n,
             "fail_ratio": sum(r.failed for r in eng_results) / n,
-            "mean_latency_s": (float(np.mean(ok)) / 1e3 if ok
-                               else float("inf")),
             # Completed background predictor fits (the hit rate itself
             # comes from the engine view).
             "predictor_fits": sum(
@@ -758,3 +783,13 @@ class EdgeServer:
             extra["device_used_mb"] = led.device_used()
             extra["device_budget_mb"] = led.budgets_mb
         return dataclasses.replace(self.engine.stats(), **extra)
+
+
+def _move_kind(before: Optional[int], after: Optional[int]) -> str:
+    """What a change of resident precision did: load, unload, upgrade or
+    downgrade."""
+    if before is None:
+        return "load"
+    if after is None:
+        return "unload"
+    return "upgrade" if after > before else "downgrade"

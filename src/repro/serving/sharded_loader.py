@@ -357,7 +357,8 @@ class ShardedLoaderChannel(BackgroundLoader):
                 del self.inflight[app]
                 continue
             del self.inflight[app]
-            ld.future.result()  # wall-clock commit move absorbed here
+            with self.tracer.span("loader.wait", tenant=app):
+                ld.future.result()  # wall-clock commit move absorbed here
             # Claims convert to committed weights in one transaction;
             # the applier walks the shard claims in device order.
             commit = A.Load(app, ld.variant, claim_mb=ld.charge_mb,

@@ -143,12 +143,10 @@ class ServingStats:
     cluster: Optional[Dict[str, Any]] = None
 
     # --- server-level gauges (EdgeServer.stats() only) ---------------
-    redispatched: Optional[int] = None
     resident_mb: Optional[float] = None
     weights_mb: Optional[float] = None
     kv_mb: Optional[float] = None
     fail_ratio: Optional[float] = None
-    mean_latency_s: Optional[float] = None
     predictor_fits: Optional[int] = None
     # Residual-adapted prediction window per tenant (adaptive-delta
     # servers only).

@@ -6,6 +6,7 @@ CPU-only machine and guards against kernels the TPU compiler refuses
 two served tenants produce.  Nothing here runs a kernel.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,11 +51,14 @@ def _spec(shape, dtype, sharding):
 ])
 def test_quant_matmul_compiles_for_v5e(one_chip, M, K, N):
     f = jax.jit(lambda x, w, s: qm.quant_matmul(x, w, s))
-    compiled = f.lower(_spec((M, K), jnp.bfloat16, one_chip),
-                       _spec((K, N), jnp.int8, one_chip),
-                       _spec((K // GROUP, N), jnp.float32, one_chip)
-                       ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    lowered = f.lower(_spec((M, K), jnp.bfloat16, one_chip),
+                      _spec((K, N), jnp.int8, one_chip),
+                      _spec((K // GROUP, N), jnp.float32, one_chip))
+    # The kernel carries its name into the program and the device trace.
+    assert 'kernel_name = "quant_matmul"' in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%quant_matmul[.\d]* = .*custom-call\(", text)
 
 
 # mamba2-780m: 48 heads of 64, one group of state 128, chunk 256; a
@@ -63,10 +67,13 @@ def test_quant_matmul_compiles_for_v5e(one_chip, M, K, N):
 def test_ssd_scan_compiles_for_v5e(one_chip, S):
     H, P, G, N = 48, 64, 1, 128
     f = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=256, return_state=True))
-    compiled = f.lower(_spec((1, S, H, P), jnp.bfloat16, one_chip),
-                       _spec((1, S, H), jnp.bfloat16, one_chip),
-                       _spec((H,), jnp.float32, one_chip),
-                       _spec((1, S, G, N), jnp.bfloat16, one_chip),
-                       _spec((1, S, G, N), jnp.bfloat16, one_chip),
-                       _spec((H,), jnp.float32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    lowered = f.lower(_spec((1, S, H, P), jnp.bfloat16, one_chip),
+                      _spec((1, S, H), jnp.bfloat16, one_chip),
+                      _spec((H,), jnp.float32, one_chip),
+                      _spec((1, S, G, N), jnp.bfloat16, one_chip),
+                      _spec((1, S, G, N), jnp.bfloat16, one_chip),
+                      _spec((H,), jnp.float32, one_chip))
+    assert 'kernel_name = "ssd_scan"' in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%ssd_scan[.\d]* = .*custom-call\(", text)
