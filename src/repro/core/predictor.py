@@ -9,7 +9,9 @@
 
 Both are the same tiny architecture (the paper calls it "edge-friendly"):
 one tanh RNN cell + linear head, trained with AdamW on sliding windows.
-No Pallas kernel is warranted here — the model is a few thousand FLOPs.
+No Pallas kernel is warranted here — the model is a few thousand FLOPs,
+so it runs on the host's CPU backend, off the accelerator the served
+models use, with each program compiled once per shape.
 """
 from __future__ import annotations
 
@@ -51,13 +53,22 @@ def rnn_forward(params: dict, xs: jnp.ndarray) -> jnp.ndarray:
     return (h @ params["wo"] + params["bo"])[:, 0]
 
 
+# One compile per (context, hidden) per process: ``params`` are
+# arguments, not a closure, and the input is always ``(1, context)``.
+_forward = jax.jit(rnn_forward)
+
+
 @functools.partial(jax.jit, static_argnames=("steps",))
-def _fit(params, opt_state, xs, ys, *, steps: int = 200):
+def _fit(params, opt_state, xs, ys, mask, *, steps: int = 200):
+    """``steps`` AdamW steps on the windows where ``mask`` is 1.  Rows
+    where it is 0 are padding: the loss is the mean over the real rows,
+    so they add nothing to it or to the gradients."""
     opt = AdamW(lr=1e-2, weight_decay=0.0, clip_norm=1.0)
+    n = jnp.sum(mask)
 
     def loss_fn(p):
         pred = rnn_forward(p, xs)
-        return jnp.mean((pred - ys) ** 2)
+        return jnp.sum(mask * (pred - ys) ** 2) / n
 
     def step(carry, _):
         p, s = carry
@@ -90,8 +101,16 @@ class SeriesPredictor:
     fit_steps: int = 150  # AdamW steps per background fit
 
     def __post_init__(self):
-        self.params = init_rnn(jax.random.key(self.seed), self.hidden)
-        self.opt_state = AdamW(lr=1e-2, weight_decay=0.0).init(self.params)
+        # The RNN lives on the host's CPU backend: its jitted programs
+        # run where their committed arguments are, so a forward pass or
+        # a fit never dispatches to (or syncs with) an accelerator the
+        # served models are using.
+        self.device = jax.local_devices(backend="cpu")[0]
+        with jax.default_device(self.device):
+            params = init_rnn(jax.random.key(self.seed), self.hidden)
+            opt_state = AdamW(lr=1e-2, weight_decay=0.0).init(params)
+        self.params, self.opt_state = jax.device_put(
+            (params, opt_state), self.device)
         self.mean = 1.0
         self.history: list[float] = []
         self.losses: Optional[np.ndarray] = None
@@ -125,14 +144,23 @@ class SeriesPredictor:
         hn = h / self.mean
         windows = np.lib.stride_tricks.sliding_window_view(
             hn, self.context + 1)
-        xs = jnp.asarray(windows[:, :-1])
-        ys = jnp.asarray(windows[:, -1])
+        # Pad the windows to the next power of two (mask 0 on the
+        # padding) so refits at a growing history reuse one compiled
+        # ``_fit`` per bucket instead of compiling once per length.
+        n = len(windows)
+        padded = np.zeros((1 << (n - 1).bit_length(), self.context + 1),
+                          np.float32)
+        padded[:n] = windows
+        mask = np.zeros(len(padded), np.float32)
+        mask[:n] = 1.0
+        xs, ys, mask = jax.device_put(
+            (padded[:, :-1], padded[:, -1], mask), self.device)
         self.params, self.opt_state, losses = _fit(
-            self.params, self.opt_state, xs, ys, steps=steps)
+            self.params, self.opt_state, xs, ys, mask, steps=steps)
         self.losses = np.asarray(losses)
         self.fits += 1
         self._fit_len = len(h)
-        return float(losses[-1])
+        return float(self.losses[-1])
 
     def predict(self) -> float:
         """Predict the next value from the trailing context.
@@ -164,8 +192,8 @@ class SeriesPredictor:
         mean = float(np.mean(ctx)) or 1.0
         if self.losses is None:  # never fit: untrained RNN is noise
             return mean
-        xs = jnp.asarray(ctx / mean)[None]
-        return float(rnn_forward(self.params, xs)[0] * mean)
+        xs = jax.device_put((ctx / mean)[None], self.device)
+        return float(np.asarray(_forward(self.params, xs))[0]) * mean
 
 
 class RequestPredictor(SeriesPredictor):
